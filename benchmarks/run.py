@@ -175,6 +175,11 @@ def main() -> int:
                     help="skip appending the sweep row to BENCH_sweep.json")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # a fixed path: the cache directory is part of the entries' key
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
 
     from benchmarks import (autotune, cache_hierarchy, corpus_sweep,
                             dram_types, dynamic_sweep,

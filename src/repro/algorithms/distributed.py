@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.algorithms.common import INF32
-from repro.distributed.context import shard_map
 from repro.graphs.formats import Graph
 
 
@@ -77,7 +76,7 @@ def make_min_step(mesh: Mesh, n_shards: int, q: int, add_weight: bool):
         new_vals = jnp.minimum(values_l, gathered)
         return new_vals[None], (new_vals != values_l).any()[None]
 
-    stepped = shard_map(
+    stepped = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P("data", None), P("data", None), P("data", None),
                   P("data", None), P("data", None)),

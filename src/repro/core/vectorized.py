@@ -114,21 +114,28 @@ SERVE_BACKENDS = ("auto", "scan", "pallas")
 def resolve_serve_backend(backend: str = "auto") -> str:
     """Resolve the ``serve_backend`` knob to ``scan`` or ``pallas``.
 
-    ``auto`` prefers the Pallas kernel on accelerator platforms and the
-    XLA scan on CPU, where the kernel could only run in interpret mode
-    (an eval loop, orders of magnitude slower — fine for parity tests,
-    wrong for serving).  ``REPRO_SERVE_BACKEND`` overrides ``auto``
+    ``auto`` is the Pallas kernel on the TPU, the platform it is written
+    for and compiles on at every preset geometry, and the XLA scan
+    everywhere else.  On the CPU the kernel could only run in interpret
+    mode (an eval loop, orders of magnitude slower — fine for parity
+    tests, wrong for serving).  An explicit ``pallas`` on a platform
+    that is neither raises.  ``REPRO_SERVE_BACKEND`` overrides ``auto``
     only; an explicit argument always wins.
     """
+    platform = jax.default_backend()
     if backend == "auto":
         env = os.environ.get("REPRO_SERVE_BACKEND", "")
-        if env in ("scan", "pallas"):
-            return env
-        return "pallas" if jax.default_backend() != "cpu" else "scan"
+        if env not in ("scan", "pallas"):
+            return "pallas" if platform == "tpu" else "scan"
+        backend = env
     if backend not in ("scan", "pallas"):
         raise ValueError(
             f"serve_backend must be one of {SERVE_BACKENDS}, got "
             f"{backend!r}")
+    if backend == "pallas" and platform not in ("tpu", "cpu"):
+        raise ValueError(
+            f"serve_backend='pallas' is a TPU kernel (interpret mode on "
+            f"the CPU) and cannot run on {platform!r}")
     return backend
 
 
@@ -606,6 +613,10 @@ def make_serve_step(timing, C, B, R, K, banks_per_rank):
     def pick(masked, axis):
         return jnp.max(masked, axis=axis)
 
+    def widen(mask, axis):
+        # Mosaic cannot reshape i1 vectors: insert the unit axis on int32
+        return jnp.expand_dims(mask.astype(jnp.int32), axis) != 0
+
     def step(state, x):
         avail, act, bus, hist, ptr, pmf = state
         iss, mt, bnd = x                                   # [C, K]
@@ -638,7 +649,7 @@ def make_serve_step(timing, C, B, R, K, banks_per_rank):
             rank_m = pick(jnp.where(mv, rank, 0), 1)       # [C]
             ohr_m = rank_m[:, None] == rank_ids            # [C, R]
             ptr_m = pick(jnp.where(ohr_m, ptr, 0), 1)
-            hist_m = pick(jnp.where(ohr_m[:, :, None], hist, NEG_INF32),
+            hist_m = pick(jnp.where(widen(ohr_m, 2), hist, NEG_INF32),
                           1)                               # [C, 4]
         ohp_m = ptr_m[:, None] == ptr_ids                  # [C, 4]
         oh_last = ((ptr_m + 3) % 4)[:, None] == ptr_ids
@@ -652,7 +663,7 @@ def make_serve_step(timing, C, B, R, K, banks_per_rank):
         col = jnp.where(ms, a + tRCD, col_hit)
         # --- shared data bus: prefix max over the block's lanes
         cadj = col + tCL - lane_tbl
-        ccm = pick(jnp.where(tril & v[:, None, :], cadj[:, None, :],
+        ccm = pick(jnp.where(tril & widen(v, 1), cadj[:, None, :],
                              NEG_INF32), 2)
         fin = lane_tbl1 + jnp.maximum(bus[:, None], ccm)
         fin_out = jnp.where(v, fin, jnp.int32(0))
@@ -661,27 +672,27 @@ def make_serve_step(timing, C, B, R, K, banks_per_rank):
         # monotone), so updates are plain maxes — no masked selects
         bus = jnp.maximum(bus, mx)
         pmf = jnp.maximum(pmf, mx)
-        vohb = ohb & v[:, :, None]
+        vohb = ohb & widen(v, 2)
         avail = jnp.maximum(
             avail,
             pick(jnp.where(vohb, (col + tBL)[:, :, None], NEG_INF32), 1))
         a_m = pick(jnp.where(mv, a, NEG_INF32), 1)         # [C]
         act = jnp.maximum(
-            act, pick(jnp.where(ohb & mv[:, :, None], a[:, :, None],
+            act, pick(jnp.where(ohb & widen(mv, 2), a[:, :, None],
                                 NEG_INF32), 1))
         if R == 1:
             hist = jnp.maximum(
-                hist, jnp.where(ohp_m & m_any[:, None],
+                hist, jnp.where(ohp_m & widen(m_any, 1),
                                 a_m[:, None], NEG_INF32)[:, None, :])
-            ptr = jnp.where(m_any[:, None], (ptr_m + 1)[:, None] % 4,
+            ptr = jnp.where(widen(m_any, 1), (ptr_m + 1)[:, None] % 4,
                             ptr)
         else:
             hist = jnp.maximum(
                 hist, jnp.where(
-                    (ohr_m[:, :, None] & ohp_m[:, None, :])
-                    & m_any[:, None, None],
+                    (widen(ohr_m, 2) & widen(ohp_m, 1))
+                    & widen(m_any, (1, 2)),
                     a_m[:, None, None], NEG_INF32))
-            ptr = jnp.where(ohr_m & m_any[:, None],
+            ptr = jnp.where(ohr_m & widen(m_any, 1),
                             ((ptr_m + 1) % 4)[:, None], ptr)
 
         # branchless phase-boundary re-base: shift = 0 off-boundary is

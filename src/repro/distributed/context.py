@@ -45,22 +45,6 @@ def use(ctx: Optional[ShardCtx]):
         _STATE.ctx = prev
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` compatibility wrapper.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; on older
-    releases the API lives in ``jax.experimental.shard_map`` and the
-    replication check is spelled ``check_rep``.  The default matches
-    jax's (check enabled); call sites opt out explicitly.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def constrain(x, name: str):
     """Apply a named sharding constraint if a context is installed."""
     ctx = current()

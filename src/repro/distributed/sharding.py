@@ -23,7 +23,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import vectorized as vec
@@ -223,11 +222,11 @@ def sharded_fused_scan_batch(issue, meta, boundary, timing, n_banks,
                              _pad_cases(boundary, pad))
     timing = _pad_cases(jnp.asarray(timing, jnp.int32), pad)
     state = _sweep_state(M + pad, C, n_banks, banks_per_rank)
-    # check_rep=False: every operand is case-sharded; there is no
+    # check_vma=False: every operand is case-sharded; there is no
     # replicated output for the checker to reason about
-    fn = shard_map(vec._fused_scan_batch, mesh=mesh,
-                   in_specs=P("cases"), out_specs=P("cases"),
-                   check_rep=False)
+    fn = jax.shard_map(vec._fused_scan_batch, mesh=mesh,
+                       in_specs=P("cases"), out_specs=P("cases"),
+                       check_vma=False)
     fins = []
     pos = 0
     for size in vec.plan_chunks(S):
@@ -259,9 +258,9 @@ def sharded_fused_scan_batch_shared(issue, meta, boundary, timing,
     boundary = jnp.asarray(boundary)
     timing = _pad_cases(jnp.asarray(timing, jnp.int32), pad)
     state = _sweep_state(M + pad, C, n_banks, banks_per_rank)
-    fn = shard_map(vec._fused_scan_batch_shared, mesh=mesh,
-                   in_specs=(P(), P(), P(), P("cases"), P("cases")),
-                   out_specs=P("cases"), check_rep=False)
+    fn = jax.shard_map(vec._fused_scan_batch_shared, mesh=mesh,
+                       in_specs=(P(), P(), P(), P("cases"), P("cases")),
+                       out_specs=P("cases"), check_vma=False)
     fins = []
     pos = 0
     for size in vec.plan_chunks(S):

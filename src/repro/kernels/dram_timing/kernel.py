@@ -24,15 +24,21 @@ Two kernels live here:
 BlockSpec tiling streams ``(tile, C, K)`` trace tiles through VMEM
 (Pallas double-buffers the next tile's copy-in behind the current tile's
 compute); the carry state stays resident in VMEM scratch across the
-whole grid.  Working set per tile at the default ``tile=512``, C=4, K=8:
-two int32 streams of 512x4x8 = 128 KiB plus O(C*B) state — far under
-the ~16 MiB VMEM budget.  Timing parameters ride as a *traced* int32[7]
+whole grid, and the boundary flags and timing scalars ride in SMEM.
+Every VMEM block is padded to the ``(8, 128)`` int32 tile, so a
+``(tile, C, K)`` block costs ``tile * ceil8(C) * 128 * 4`` bytes however
+small K is: 4 MiB at ``tile=1024`` for C <= 8, 8 MiB at C=16.  The
+three streams (issue, meta in; finish out), double-buffered, therefore
+need 24 MiB (C <= 8) to 48 MiB (C=16) of scoped VMEM, above the 16 MiB
+default; :func:`_serve_vmem_bytes` computes the bound and the kernel
+asks for it explicitly.  Timing parameters ride as a *traced* int32[7]
 input (never static), so one compiled kernel serves every speed grade.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +51,32 @@ NEG_INF32 = -(1 << 30)
 
 #: steps per serve-kernel grid tile.  Both fused-scan chunk-ladder sizes
 #: (2**13, 2**17) are multiples, so ladder chunks always tile exactly.
-SERVE_TILE = 512
+#: 1024 is also the tile of XLA's TPU layout for the int32[S] boundary
+#: stream (``T(1024)``); a compiled kernel's 1-D block must match it.
+SERVE_TILE = 1024
+
+#: headroom above the blocks' bytes for Mosaic's own scoped scratch
+_VMEM_HEADROOM = 2 << 20
+
+
+def _padded_bytes(shape) -> int:
+    """VMEM bytes of an int32 block padded to the ``(8, 128)`` tile."""
+    *lead, sub, lane = (1, 1) + tuple(shape)
+    return (math.prod(lead) * (-(-sub // 8) * 8)
+            * (-(-lane // 128) * 128) * 4)
+
+
+def _carry_shapes(C: int, B: int, R: int):
+    return [(C, B), (C, B), (C,), (C, R, 4), (C, R), (C,)]
+
+
+def _serve_vmem_bytes(tile: int, C: int, K: int, B: int, R: int) -> int:
+    """Scoped-VMEM bound of :func:`dram_serve_kernel`: the three
+    ``(tile, C, K)`` streams and the carry blocks in and out, each
+    double-buffered, plus the resident carry scratch, all padded to the
+    ``(8, 128)`` tile."""
+    carry = sum(_padded_bytes(s) for s in _carry_shapes(C, B, R))
+    return 2 * (3 * _padded_bytes((tile, C, K)) + 2 * carry) + carry
 
 
 def _kernel(issue_ref, bank_ref, row_ref, valid_ref, timing_ref,
@@ -74,13 +105,13 @@ def _kernel(issue_ref, bank_ref, row_ref, valid_ref, timing_ref,
         v = valid_ref[0, j]
         rank = b // banks_per_rank
 
-        o = pl.load(open_row, (b,))
-        at = pl.load(act_time, (b,))
-        av = pl.load(bank_avail, (b,))
+        o = open_row[b]
+        at = act_time[b]
+        av = bank_avail[b]
         bf = bus_free[0]
-        ptr = pl.load(act_ptr, (rank,))
-        la = pl.load(last_act, (rank,))
-        oldest = pl.load(act_hist, (rank, ptr))
+        ptr = act_ptr[rank]
+        la = last_act[rank]
+        oldest = act_hist[rank, ptr]
 
         hit = o == r
         empty = o == -1
@@ -97,15 +128,13 @@ def _kernel(issue_ref, bank_ref, row_ref, valid_ref, timing_ref,
         did_act = jnp.logical_and(jnp.logical_not(hit), v)
 
         upd = jnp.logical_and(v, True)
-        pl.store(open_row, (b,), jnp.where(upd & ~hit, r, o))
-        pl.store(act_time, (b,), jnp.where(did_act, act, at))
-        pl.store(bank_avail, (b,), jnp.where(upd, col + tBL, av))
+        open_row[b] = jnp.where(upd & ~hit, r, o)
+        act_time[b] = jnp.where(did_act, act, at)
+        bank_avail[b] = jnp.where(upd, col + tBL, av)
         bus_free[0] = jnp.where(upd, finish, bf)
-        pl.store(act_hist, (rank, ptr),
-                 jnp.where(did_act, act, oldest))
-        pl.store(act_ptr, (rank,),
-                 jnp.where(did_act, (ptr + 1) % 4, ptr))
-        pl.store(last_act, (rank,), jnp.where(did_act, act, la))
+        act_hist[rank, ptr] = jnp.where(did_act, act, oldest)
+        act_ptr[rank] = jnp.where(did_act, (ptr + 1) % 4, ptr)
+        last_act[rank] = jnp.where(did_act, act, la)
 
         finish_ref[0, j] = jnp.where(v, finish, 0)
         kind_ref[0, j] = jnp.where(v, kind, -1)
@@ -180,8 +209,10 @@ def _serve_kernel(issue_ref, meta_ref, boundary_ref, timing_ref,
     C, B = avail_s.shape
     R = hist_s.shape[1]
     K = issue_ref.shape[2]
-    step = vec.make_serve_step(timing_ref[...], C, B, R, K,
-                               banks_per_rank)
+    # timing and boundary live in SMEM: scalar reads at a dynamic index
+    # are not expressible as vector loads from a 1-D VMEM ref
+    timing = [timing_ref[i] for i in range(len(vec.TIMING_FIELDS))]
+    step = vec.make_serve_step(timing, C, B, R, K, banks_per_rank)
 
     def body(j, _):
         state = (avail_s[...], act_s[...], bus_s[...], hist_s[...],
@@ -227,6 +258,10 @@ def dram_serve_kernel(
     """
     S, C, K = issue.shape
     assert S % tile == 0, (S, tile)
+    if not interpret and tile % SERVE_TILE:
+        raise ValueError(
+            f"a compiled serve kernel needs tile % {SERVE_TILE} == 0 "
+            f"(the boundary stream's TPU layout), got {tile}")
     B = avail.shape[1]
     R = hist.shape[1]
     grid = (S // tile,)
@@ -236,18 +271,26 @@ def dram_serve_kernel(
         ix = tuple(0 for _ in shape)
         return pl.BlockSpec(shape, lambda t, _ix=ix: _ix)
 
-    carry_shapes = [(C, B), (C, B), (C,), (C, R, 4), (C, R), (C,)]
+    carry_shapes = _carry_shapes(C, B, R)
     kern = functools.partial(_serve_kernel, tile=tile,
                              banks_per_rank=banks_per_rank)
     out = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[stream, stream, pl.BlockSpec((tile,), lambda t: (t,)),
-                  whole((7,))] + [whole(s) for s in carry_shapes],
+        in_specs=[stream, stream,
+                  pl.BlockSpec((tile,), lambda t: (t,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [whole(s) for s in carry_shapes],
         out_specs=[stream] + [whole(s) for s in carry_shapes],
         out_shape=[jax.ShapeDtypeStruct((S, C, K), jnp.int32)]
         + [jax.ShapeDtypeStruct(s, jnp.int32) for s in carry_shapes],
         scratch_shapes=[pltpu.VMEM(s, jnp.int32) for s in carry_shapes],
+        compiler_params=pltpu.CompilerParams(
+            # the carry chains through scratch: tiles run in order
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_serve_vmem_bytes(tile, C, K, B, R)
+            + _VMEM_HEADROOM),
         interpret=interpret,
     )(issue.astype(jnp.int32), meta.astype(jnp.int32),
       boundary.astype(jnp.int32), timing.astype(jnp.int32),

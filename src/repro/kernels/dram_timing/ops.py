@@ -3,9 +3,9 @@
 The layering contract (see ``src/repro/kernels/README.md``): kernel.py
 holds the raw ``pallas_call`` builders (explicit ``interpret`` bool),
 ref.py the pure-jnp oracles, and this module the public ops — jitted,
-with ``interpret="auto"`` resolved from the platform (compiled on
-TPU/GPU, interpret mode on CPU, where compiling a TPU kernel is simply
-impossible — interpret is *mandatory* there, not a preference).
+with interpret mode taken from the platform alone: compiled on the TPU,
+interpreted on the CPU, where compiling a TPU kernel is impossible.
+There is no knob, so interpret mode is never reachable on the chip.
 
 Timing parameters are **traced** int32[7] inputs, never static jit
 arguments: one compiled kernel serves every DDR3/DDR4/HBM speed grade.
@@ -31,13 +31,10 @@ from repro.kernels.dram_timing.kernel import (SERVE_TILE,
                                               dram_timing_kernel)
 
 
-def resolve_interpret(interpret="auto") -> bool:
-    """Resolve the ``interpret`` knob: ``"auto"`` means compiled on
-    accelerator platforms and interpret mode on CPU (where it is the
-    only way to execute the kernel body at all)."""
-    if interpret == "auto":
-        return jax.default_backend() == "cpu"
-    return bool(interpret)
+def _interpret() -> bool:
+    """Interpret mode on the CPU, the only way to run the kernel body
+    there; compiled everywhere else."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(
@@ -52,14 +49,14 @@ def _dram_timing(issue, bank, row, valid, timing, *, n_banks,
 
 
 def dram_timing(issue, bank, row, valid, timing, *, n_banks,
-                banks_per_rank, chunk=512, interpret="auto"):
+                banks_per_rank, chunk=512):
     """Per-channel ``[C, L]`` timing scan (one request per channel per
     step).  ``timing`` is the traced int32[7] vector; returns
     ``(finish, kind)`` int32[C, L]."""
     return _dram_timing(
         issue, bank, row, valid, jnp.asarray(timing, dtype=jnp.int32),
         n_banks=n_banks, banks_per_rank=banks_per_rank, chunk=chunk,
-        interpret=resolve_interpret(interpret))
+        interpret=_interpret())
 
 
 @functools.partial(
@@ -73,7 +70,7 @@ def _dram_serve(issue, meta, boundary, timing, avail, act, bus, hist,
 
 
 def dram_serve(issue, meta, boundary, timing, state, *, banks_per_rank,
-               tile=SERVE_TILE, interpret="auto"):
+               tile=SERVE_TILE):
     """Serve one fused-scan chunk of blocked ``[S, C, K]`` lockstep
     streams through the Pallas serve kernel.
 
@@ -96,12 +93,12 @@ def dram_serve(issue, meta, boundary, timing, state, *, banks_per_rank,
     fin, state = _dram_serve(
         issue, meta, boundary, jnp.asarray(timing, dtype=jnp.int32),
         *state, banks_per_rank=banks_per_rank, tile=tile,
-        interpret=resolve_interpret(interpret))
+        interpret=_interpret())
     return fin[:S], state
 
 
 def simulate_trace_kernel(trace: Trace, cfg: DRAMConfig,
-                          chunk: int = 512, interpret="auto"):
+                          chunk: int = 512):
     """End-to-end: Trace -> per-channel pack -> kernel -> makespan."""
     packed = pack_channels(trace, cfg)
     C, L = packed.issue.shape
@@ -118,7 +115,7 @@ def simulate_trace_kernel(trace: Trace, cfg: DRAMConfig,
         jnp.asarray(_pad(packed.issue)), jnp.asarray(_pad(packed.bank)),
         jnp.asarray(_pad(packed.row)), jnp.asarray(_pad(packed.valid)),
         timing, n_banks=cfg.banks_per_channel,
-        banks_per_rank=cfg.org.banks, chunk=chunk, interpret=interpret,
+        banks_per_rank=cfg.org.banks, chunk=chunk,
     )
     finish = np.asarray(finish)[:, :L]
     kind = np.asarray(kind)[:, :L]

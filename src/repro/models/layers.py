@@ -374,7 +374,7 @@ def _moe_ep_shard_map(x2, p, cfg: ModelConfig, ctx: dctx.ShardCtx):
         return jnp.einsum("tec,ecd->td", combine, back)
 
     fs = fsdp_axes if fsdp_axes else None
-    fx = dctx.shard_map(
+    fx = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(tok_axes, None), P(None, None),
                   P(ctx.expert_axis, None, fs),
@@ -432,7 +432,7 @@ def _moe_ep_psum(x2, p, cfg: ModelConfig, ctx: dctx.ShardCtx):
         return jax.lax.psum(y_partial, ctx.expert_axis)
 
     fs = fsdp_axes if fsdp_axes else None
-    fx = dctx.shard_map(
+    fx = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(tok_axes, None), P(None, None),
                   P(ctx.expert_axis, None, fs),

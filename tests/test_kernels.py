@@ -117,6 +117,21 @@ class TestDramServeKernel:
         self._assert_parity(cfg, _random_serve_program(rng, n_phases=3),
                             tile=tile)
 
+    def test_compiled_tile_must_match_boundary_layout(self):
+        """A compiled kernel's 1-D boundary block must be a multiple of
+        XLA's 1024-element TPU tile; interpret mode takes any tile."""
+        from repro.kernels.dram_timing.kernel import dram_serve_kernel
+        C, B, R = 1, 16, 1
+        state = tuple(vec.init_lean_carry(C, B, B // R)) + (
+            jnp.zeros((C,), dtype=jnp.int32),)
+        S = 1024
+        with pytest.raises(ValueError, match="tile"):
+            dram_serve_kernel(
+                jnp.zeros((S, C, 1), jnp.int32),
+                jnp.zeros((S, C, 1), jnp.int32), jnp.zeros(S, jnp.int32),
+                jnp.asarray(vec.timing_params(ddr4_2400r().timing)),
+                *state, banks_per_rank=B // R, tile=512, interpret=False)
+
     @settings(max_examples=10, deadline=None)
     @given(seed=st.integers(0, 10**6), tRRD=st.integers(1, 8),
            tFAW=st.integers(4, 40))

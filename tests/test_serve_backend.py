@@ -66,9 +66,17 @@ class TestServeBackendKnob:
 
     def test_resolve_auto_platform(self, monkeypatch):
         monkeypatch.delenv("REPRO_SERVE_BACKEND", raising=False)
-        import jax
-        expect = "pallas" if jax.default_backend() != "cpu" else "scan"
-        assert vec.resolve_serve_backend("auto") == expect
+        for platform, expect in (("cpu", "scan"), ("tpu", "pallas"),
+                                 ("gpu", "scan")):
+            monkeypatch.setattr(vec.jax, "default_backend",
+                                lambda p=platform: p)
+            assert vec.resolve_serve_backend("auto") == expect, platform
+
+    def test_resolve_explicit_pallas_off_tpu_raises(self, monkeypatch):
+        monkeypatch.setattr(vec.jax, "default_backend", lambda: "gpu")
+        assert vec.resolve_serve_backend("scan") == "scan"
+        with pytest.raises(ValueError, match="TPU kernel"):
+            vec.resolve_serve_backend("pallas")
 
     def test_resolve_auto_env_override(self, monkeypatch):
         monkeypatch.setenv("REPRO_SERVE_BACKEND", "pallas")
