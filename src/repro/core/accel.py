@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import cache as cache_mod
 from repro.core.dram import DRAMConfig, CACHE_LINE_BYTES
 from repro.core.trace import SegmentedTrace, Trace
@@ -331,8 +332,9 @@ def pack_program_device(program: SegmentedTrace, cfg: DRAMConfig,
         jnp.asarray(line32), jnp.asarray(issue32),
         jnp.asarray(offsets32), jnp.int32(N), open_row,
         spec=spec, C=C, B=B, banks=cfg.org.banks)
-    S = int(S)
-    K = int(K)
+    with obs.span(obs.DEVICE_WAIT):
+        S = int(S)
+        K = int(K)
     S_pad = sum(vec.plan_chunks(S))
     issue_d, meta_d, boundary_d = vec._device_pack_scatter(
         r_idx, c_idx, lane, issue_s, meta_s, valid_s, L_p,
@@ -368,20 +370,21 @@ def pack_program_auto(program: SegmentedTrace, cfg: DRAMConfig,
     ``"host"`` (the NumPy reference), or ``"auto"`` (platform heuristic,
     see :func:`_auto_pack_prefers_device`; host whenever the device path
     does not support the program/geometry)."""
-    if backend == "auto":
-        backend = ("device" if _auto_pack_prefers_device() else "host")
-        if backend == "device" and not device_pack_supported(program,
-                                                            cfg):
-            backend = "host"
-    if backend == "host":
-        if open_row is not None:
-            open_row = np.asarray(open_row)
-        return pack_program(program, cfg, open_row=open_row)
-    if not device_pack_supported(program, cfg):
-        raise ValueError(
-            "program/device not eligible for the device pack path "
-            "(non-pow2 geometry, >256 banks, or addresses beyond int32)")
-    return pack_program_device(program, cfg, open_row=open_row)
+    with obs.span(obs.PACK):
+        if backend == "auto":
+            backend = ("device" if _auto_pack_prefers_device() else "host")
+            if backend == "device" and not device_pack_supported(program,
+                                                                cfg):
+                backend = "host"
+        if backend == "host":
+            if open_row is not None:
+                open_row = np.asarray(open_row)
+            return pack_program(program, cfg, open_row=open_row)
+        if not device_pack_supported(program, cfg):
+            raise ValueError(
+                "program/device not eligible for the device pack path "
+                "(non-pow2 geometry, >256 banks, or addresses beyond int32)")
+        return pack_program_device(program, cfg, open_row=open_row)
 
 
 @dataclasses.dataclass
@@ -420,29 +423,32 @@ def finalize_program(packed: PackedProgram, finish,
     invalid lanes), so each phase's makespan is a segmented max; row
     hits/conflicts reduce from the host-precomputed kinds.  The absolute
     clock is the running (int64, overflow-free) sum of makespans."""
-    P = packed.n_phases
-    fin = np.asarray(finish)[:packed.n_steps].max(axis=(1, 2))
-    dur = np.maximum.reduceat(fin, packed.step_starts).astype(np.int64)
-    off = packed.offsets[:-1]
-    hits = np.add.reduceat((packed.kind == 0).astype(np.int64), off)
-    confl = np.add.reduceat((packed.kind == 2).astype(np.int64), off)
-    ends = origin + np.cumsum(dur)
-    starts = ends - dur
-    phases = [
-        PhaseStats(
-            name=packed.names[p], requests=int(packed.requests[p]),
-            bytes=int(packed.requests[p]) * CACHE_LINE_BYTES,
-            start_cycle=int(starts[p]), end_cycle=int(ends[p]),
-            row_hits=int(hits[p]), row_conflicts=int(confl[p]),
+    with obs.span(obs.REDUCE):
+        with obs.span(obs.DEVICE_WAIT):
+            finish = np.asarray(finish)
+        P = packed.n_phases
+        fin = finish[:packed.n_steps].max(axis=(1, 2))
+        dur = np.maximum.reduceat(fin, packed.step_starts).astype(np.int64)
+        off = packed.offsets[:-1]
+        hits = np.add.reduceat((packed.kind == 0).astype(np.int64), off)
+        confl = np.add.reduceat((packed.kind == 2).astype(np.int64), off)
+        ends = origin + np.cumsum(dur)
+        starts = ends - dur
+        phases = [
+            PhaseStats(
+                name=packed.names[p], requests=int(packed.requests[p]),
+                bytes=int(packed.requests[p]) * CACHE_LINE_BYTES,
+                start_cycle=int(starts[p]), end_cycle=int(ends[p]),
+                row_hits=int(hits[p]), row_conflicts=int(confl[p]),
+            )
+            for p in range(P)
+        ]
+        return ProgramStats(
+            phases=phases, now=int(ends[-1]) if P else origin,
+            total_requests=int(packed.requests.sum()),
+            total_row_hits=int(hits.sum()),
+            total_row_conflicts=int(confl.sum()),
         )
-        for p in range(P)
-    ]
-    return ProgramStats(
-        phases=phases, now=int(ends[-1]) if P else origin,
-        total_requests=int(packed.requests.sum()),
-        total_row_hits=int(hits.sum()),
-        total_row_conflicts=int(confl.sum()),
-    )
 
 
 def finalize_program_device(packed: DevicePackedProgram, finish,
@@ -451,29 +457,31 @@ def finalize_program_device(packed: DevicePackedProgram, finish,
     makespans reduce on device (``finish`` is the device finish array the
     fused scan produced with ``as_numpy=False``); only ``O(P)`` integers
     cross to the host."""
-    P = packed.n_phases
-    dur = np.asarray(
-        vec._device_phase_durations(finish, packed.L_p)
-    )[:P].astype(np.int64)
-    hits = np.asarray(packed.hits_p)[:P].astype(np.int64)
-    confl = np.asarray(packed.confl_p)[:P].astype(np.int64)
-    ends = origin + np.cumsum(dur)
-    starts = ends - dur
-    phases = [
-        PhaseStats(
-            name=packed.names[p], requests=int(packed.requests[p]),
-            bytes=int(packed.requests[p]) * CACHE_LINE_BYTES,
-            start_cycle=int(starts[p]), end_cycle=int(ends[p]),
-            row_hits=int(hits[p]), row_conflicts=int(confl[p]),
+    with obs.span(obs.REDUCE):
+        dur = vec._device_phase_durations(finish, packed.L_p)
+        with obs.span(obs.DEVICE_WAIT):
+            dur = np.asarray(dur)
+        P = packed.n_phases
+        dur = dur[:P].astype(np.int64)
+        hits = np.asarray(packed.hits_p)[:P].astype(np.int64)
+        confl = np.asarray(packed.confl_p)[:P].astype(np.int64)
+        ends = origin + np.cumsum(dur)
+        starts = ends - dur
+        phases = [
+            PhaseStats(
+                name=packed.names[p], requests=int(packed.requests[p]),
+                bytes=int(packed.requests[p]) * CACHE_LINE_BYTES,
+                start_cycle=int(starts[p]), end_cycle=int(ends[p]),
+                row_hits=int(hits[p]), row_conflicts=int(confl[p]),
+            )
+            for p in range(P)
+        ]
+        return ProgramStats(
+            phases=phases, now=int(ends[-1]) if P else origin,
+            total_requests=int(packed.requests.sum()),
+            total_row_hits=int(hits.sum()),
+            total_row_conflicts=int(confl.sum()),
         )
-        for p in range(P)
-    ]
-    return ProgramStats(
-        phases=phases, now=int(ends[-1]) if P else origin,
-        total_requests=int(packed.requests.sum()),
-        total_row_hits=int(hits.sum()),
-        total_row_conflicts=int(confl.sum()),
-    )
 
 
 def serve_packed(packed, timing=None, carry=None,

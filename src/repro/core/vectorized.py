@@ -41,13 +41,13 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-import threading
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.dram import DRAMConfig, DRAMTiming, CACHE_LINE_BYTES
 from repro.core import timing as timing_mod
 from repro.core.trace import Trace, group_ranks
@@ -73,34 +73,31 @@ def choose_block_lanes(n_miss: int, n: int) -> int:
     serialize (almost every block would be a singleton miss anyway)."""
     return BLOCK_LANES if 2 * n_miss < n else 1
 
-#: jitted-scan dispatch counters (see :func:`dispatch_counts`); the
-#: throughput benchmark asserts a run costs a few fused chunk dispatches,
-#: never the legacy two per iteration.  ``device_pack`` counts whole
+#: jitted-scan dispatch counters (see :func:`dispatch_counts`; kept in
+#: :mod:`repro.obs` beside the serve's lane counter); the throughput
+#: benchmark asserts a run costs a few fused chunk dispatches, never the
+#: legacy two per iteration.  ``device_pack`` counts whole
 #: device-resident pack invocations (each is two jitted dispatches:
 #: classify+blocks, then the scatter).  ``pallas`` counts fused-serve
 #: chunks dispatched through the Pallas kernel instead of the XLA scan.
-DISPATCHES = {"packed": 0, "fused": 0, "fused_batch": 0,
-              "device_pack": 0, "pallas": 0}
-
-_DISPATCH_LOCK = threading.Lock()
+DISPATCH_KINDS = ("packed", "fused", "fused_batch", "device_pack",
+                  "pallas")
 
 
 def count_dispatch(kind: str, n: int = 1) -> None:
     """Thread-safe counter bump (the sweep engine serves independent
     batch groups from worker threads)."""
-    with _DISPATCH_LOCK:
-        DISPATCHES[kind] += n
+    obs.count(kind, n)
 
 
 def dispatch_counts() -> Dict[str, int]:
-    with _DISPATCH_LOCK:
-        return dict(DISPATCHES)
+    c = obs.counts()
+    return {k: c[k] for k in DISPATCH_KINDS}
 
 
 def reset_dispatch_counts() -> None:
-    with _DISPATCH_LOCK:
-        for k in DISPATCHES:
-            DISPATCHES[k] = 0
+    """Zero every counter of :mod:`repro.obs`, the lane counter too."""
+    obs.reset()
 
 
 #: legal values of the ``serve_backend`` knob (``DRAMConfig`` field /
@@ -706,6 +703,12 @@ def make_serve_step(timing, C, B, R, K, banks_per_rank):
     return step
 
 
+def _host_fin(fin):
+    """A chunk's finish array read back to the host."""
+    with obs.span(obs.DEVICE_WAIT):
+        return np.asarray(fin)
+
+
 def _concat_fins(fins, as_numpy, axis=0):
     """Join per-chunk finish arrays on the requested side of the
     host/device boundary (shared epilogue of the fused-scan wrappers)."""
@@ -765,22 +768,25 @@ def fused_scan(issue, meta, boundary, timing, carry, as_numpy=True,
     state = tuple(carry) + (jnp.zeros((C,), dtype=jnp.int32),)
     timing = jnp.asarray(timing, dtype=jnp.int32)
     banks_per_rank = carry[0].shape[1] // carry[3].shape[1]
+    K = issue.shape[2]
     fins = []
     pos = 0
-    for size in plan_chunks(issue.shape[0]):
-        chunk = (jnp.asarray(issue[pos:pos + size]),
-                 jnp.asarray(meta[pos:pos + size]),
-                 jnp.asarray(boundary[pos:pos + size]))
-        if backend == "pallas":
-            count_dispatch("pallas")
-            fin, state = dram_serve(*chunk, timing, state,
-                                    banks_per_rank=banks_per_rank)
-        else:
-            count_dispatch("fused")
-            fin, state = _fused_scan(*chunk, timing, state)
-        fins.append(np.asarray(fin) if as_numpy else fin)
-        pos += size
-    return _concat_fins(fins, as_numpy), state[:5]
+    with obs.span(obs.SERVE):
+        for size in plan_chunks(issue.shape[0]):
+            chunk = (jnp.asarray(issue[pos:pos + size]),
+                     jnp.asarray(meta[pos:pos + size]),
+                     jnp.asarray(boundary[pos:pos + size]))
+            obs.count("serve_lane_slots", size * C * K)
+            if backend == "pallas":
+                count_dispatch("pallas")
+                fin, state = dram_serve(*chunk, timing, state,
+                                        banks_per_rank=banks_per_rank)
+            else:
+                count_dispatch("fused")
+                fin, state = _fused_scan(*chunk, timing, state)
+            fins.append(_host_fin(fin) if as_numpy else fin)
+            pos += size
+        return _concat_fins(fins, as_numpy), state[:5]
 
 
 @jax.jit
@@ -819,15 +825,17 @@ def fused_scan_batch(issue, meta, boundary, timing, n_banks,
     timing = jnp.asarray(timing, dtype=jnp.int32)
     fins = []
     pos = 0
-    for size in plan_chunks(S):
-        count_dispatch("fused_batch")
-        fin, state = _fused_scan_batch(
-            jnp.asarray(issue[:, pos:pos + size]),
-            jnp.asarray(meta[:, pos:pos + size]),
-            jnp.asarray(boundary[:, pos:pos + size]), timing, state)
-        fins.append(np.asarray(fin) if as_numpy else fin)
-        pos += size
-    return _concat_fins(fins, as_numpy, axis=1), state[:5]
+    with obs.span(obs.SERVE):
+        for size in plan_chunks(S):
+            count_dispatch("fused_batch")
+            obs.count("serve_lane_slots", M * size * C * K)
+            fin, state = _fused_scan_batch(
+                jnp.asarray(issue[:, pos:pos + size]),
+                jnp.asarray(meta[:, pos:pos + size]),
+                jnp.asarray(boundary[:, pos:pos + size]), timing, state)
+            fins.append(_host_fin(fin) if as_numpy else fin)
+            pos += size
+        return _concat_fins(fins, as_numpy, axis=1), state[:5]
 
 
 def fused_scan_batch_shared(issue, meta, boundary, timing, n_banks,
@@ -848,15 +856,17 @@ def fused_scan_batch_shared(issue, meta, boundary, timing, n_banks,
     timing = jnp.asarray(timing, dtype=jnp.int32)
     fins = []
     pos = 0
-    for size in plan_chunks(S):
-        count_dispatch("fused_batch")
-        fin, state = _fused_scan_batch_shared(
-            jnp.asarray(issue[pos:pos + size]),
-            jnp.asarray(meta[pos:pos + size]),
-            jnp.asarray(boundary[pos:pos + size]), timing, state)
-        fins.append(np.asarray(fin) if as_numpy else fin)
-        pos += size
-    return _concat_fins(fins, as_numpy, axis=1), state[:5]
+    with obs.span(obs.SERVE):
+        for size in plan_chunks(S):
+            count_dispatch("fused_batch")
+            obs.count("serve_lane_slots", M * size * C * K)
+            fin, state = _fused_scan_batch_shared(
+                jnp.asarray(issue[pos:pos + size]),
+                jnp.asarray(meta[pos:pos + size]),
+                jnp.asarray(boundary[pos:pos + size]), timing, state)
+            fins.append(_host_fin(fin) if as_numpy else fin)
+            pos += size
+        return _concat_fins(fins, as_numpy, axis=1), state[:5]
 
 
 def simulate_trace_jax(

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import vectorized as vec
 from repro.distributed import context as dctx
 from repro.models.config import ModelConfig
@@ -208,6 +209,17 @@ def _sweep_state(M, C, n_banks, banks_per_rank):
         single + (jnp.zeros((C,), dtype=jnp.int32),))
 
 
+def _gather_fins(fins, state, M, as_numpy):
+    """Join the chunks' finish arrays and drop the padding cases."""
+    fin = (fins[0] if len(fins) == 1
+           else jnp.concatenate(fins, axis=1))[:M]
+    state = jax.tree.map(lambda x: x[:M], state[:5])
+    if as_numpy:
+        with obs.span(obs.DEVICE_WAIT):
+            fin = np.asarray(fin)
+    return fin, state
+
+
 def sharded_fused_scan_batch(issue, meta, boundary, timing, n_banks,
                              banks_per_rank, mesh: Mesh,
                              as_numpy=True):
@@ -229,17 +241,17 @@ def sharded_fused_scan_batch(issue, meta, boundary, timing, n_banks,
                        check_vma=False)
     fins = []
     pos = 0
-    for size in vec.plan_chunks(S):
-        vec.count_dispatch("fused_batch")
-        fin, state = fn(issue[:, pos:pos + size],
-                        meta[:, pos:pos + size],
-                        boundary[:, pos:pos + size], timing, state)
-        fins.append(fin)
-        pos += size
-    fin = (fins[0] if len(fins) == 1
-           else jnp.concatenate(fins, axis=1))[:M]
-    state = jax.tree.map(lambda x: x[:M], state[:5])
-    return (np.asarray(fin) if as_numpy else fin), state
+    with obs.span(obs.SERVE):
+        for size in vec.plan_chunks(S):
+            vec.count_dispatch("fused_batch")
+            # the padding replicas run too: their lanes are attempts
+            obs.count("serve_lane_slots", (M + pad) * size * C * K)
+            fin, state = fn(issue[:, pos:pos + size],
+                            meta[:, pos:pos + size],
+                            boundary[:, pos:pos + size], timing, state)
+            fins.append(fin)
+            pos += size
+        return _gather_fins(fins, state, M, as_numpy)
 
 
 def sharded_fused_scan_batch_shared(issue, meta, boundary, timing,
@@ -263,16 +275,15 @@ def sharded_fused_scan_batch_shared(issue, meta, boundary, timing,
                        out_specs=P("cases"), check_vma=False)
     fins = []
     pos = 0
-    for size in vec.plan_chunks(S):
-        vec.count_dispatch("fused_batch")
-        fin, state = fn(issue[pos:pos + size], meta[pos:pos + size],
-                        boundary[pos:pos + size], timing, state)
-        fins.append(fin)
-        pos += size
-    fin = (fins[0] if len(fins) == 1
-           else jnp.concatenate(fins, axis=1))[:M]
-    state = jax.tree.map(lambda x: x[:M], state[:5])
-    return (np.asarray(fin) if as_numpy else fin), state
+    with obs.span(obs.SERVE):
+        for size in vec.plan_chunks(S):
+            vec.count_dispatch("fused_batch")
+            obs.count("serve_lane_slots", (M + pad) * size * C * K)
+            fin, state = fn(issue[pos:pos + size], meta[pos:pos + size],
+                            boundary[pos:pos + size], timing, state)
+            fins.append(fin)
+            pos += size
+        return _gather_fins(fins, state, M, as_numpy)
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,7 @@ import dataclasses
 from concurrent.futures import Future
 from typing import Dict, Optional
 
+from repro import obs
 from repro.algorithms.common import Problem, RunResult
 from repro.analysis import locks
 from repro.core import cache as cache_mod
@@ -163,27 +164,31 @@ class SimSession:
         structure and clock, across every timing AND cache variant of
         one memory point (the cache filter runs downstream of trace
         emission)."""
+        def _build():
+            with obs.span(obs.TRACE_BUILD):
+                return spec.build_model(self.graph, config)
+
         key = _dram_cfg_key(spec.name, config, include_cache=False)
         if key is None:
             try:
                 key = (spec.name, config)
                 hash(key)
             except TypeError:
-                return spec.build_model(self.graph, config)
-        return self._singleflight(
-            self._models, key,
-            lambda: spec.build_model(self.graph, config))
+                return _build()
+        return self._singleflight(self._models, key, _build)
 
     def algorithm_run(self, spec, problem: Problem, config, root: int,
                       fixed_iters: Optional[int]) -> RunResult:
+        def _build():
+            with obs.span(obs.ALGORITHM):
+                return spec.run_algorithm(self.graph, problem, config,
+                                          root=root,
+                                          fixed_iters=fixed_iters)
+
         key = spec.algorithm_key(self.graph, problem, config, root=root,
                                  fixed_iters=fixed_iters)
-        return self._singleflight(
-            self._runs, key,
-            lambda: spec.run_algorithm(self.graph, problem, config,
-                                       root=root,
-                                       fixed_iters=fixed_iters),
-            count=("algo_runs", "algo_cache_hits"))
+        return self._singleflight(self._runs, key, _build,
+                                  count=("algo_runs", "algo_cache_hits"))
 
     def packed_program_for(self, spec, problem: Problem, config, model,
                            run: RunResult, dram, root: int = 0,
@@ -200,11 +205,13 @@ class SimSession:
         (nor the cache filter, which sees only addresses, program order,
         and timing-independent issue bounds) depends on timing."""
         def _build():
-            program = model.build_program(problem, run)
+            with obs.span(obs.TRACE_BUILD):
+                program = model.build_program(problem, run)
             cs = None
             if dram.cache is not None and dram.cache.enabled:
-                program, cs, _ = cache_mod.filter_program(
-                    program, dram.cache)
+                with obs.span(obs.CACHE_FILTER):
+                    program, cs, _ = cache_mod.filter_program(
+                        program, dram.cache)
             return pack_program_auto(program, dram), cs
 
         cfg_key = _dram_cfg_key(spec.name, config, include_cache=True)

@@ -39,6 +39,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.algorithms.common import Problem
 from repro.analysis import locks
 from repro.core import vectorized as vec
@@ -390,26 +391,28 @@ class Sweeper:
         # one stats sync per run, at the drain boundary — the finally
         # keeps interrupted/failed sweeps' partial counters visible
         # without paying a per-case re-sum (see _sync_stats)
-        try:
-            if backend in (None, "vectorized"):
-                if self.batch_memories:
-                    rows = self._run_batched(cases, control)
+        with obs.span(obs.SWEEP_RUN):
+            try:
+                if backend in (None, "vectorized"):
+                    if self.batch_memories:
+                        rows = self._run_batched(cases, control)
+                    else:
+                        rows = self._run_pipelined(cases, control)
                 else:
-                    rows = self._run_pipelined(cases, control)
-            else:
-                order = sorted(
-                    range(len(cases)),
-                    key=lambda i: (cases[i].accelerator,
-                                   cases[i].graph.fingerprint))
-                rows = [None] * len(cases)
-                for i in order:
-                    self._check_control(control, rows)
-                    rows[i] = self._guard(
-                        i, cases[i],
-                        lambda: self.run_case(cases[i], backend=backend))
-        finally:
-            self._sync_stats()
-        return rows
+                    order = sorted(
+                        range(len(cases)),
+                        key=lambda i: (cases[i].accelerator,
+                                       cases[i].graph.fingerprint))
+                    rows = [None] * len(cases)
+                    for i in order:
+                        self._check_control(control, rows)
+                        rows[i] = self._guard(
+                            i, cases[i],
+                            lambda: self.run_case(cases[i],
+                                                  backend=backend))
+            finally:
+                self._sync_stats()
+            return rows
 
     def _prepare_case(self, case: SweepCase):
         """Build ``(model, run, packed, cache_stats, dram)`` for a
@@ -483,7 +486,8 @@ class Sweeper:
                 while pending:
                     self._check_control(control, rows)
                     i, fut = pending.popleft()
-                    prepped, prep_s = fut.result()
+                    with obs.span(obs.WAIT_PREPARE):
+                        prepped, prep_s = fut.result()
                     submit_next()
                     case = cases[i]
                     if prepped is None:
@@ -530,7 +534,8 @@ class Sweeper:
             return out, time.perf_counter() - t0
 
         self._check_control(control, rows)
-        with ThreadPoolExecutor(max_workers=self.workers) as pool:
+        with obs.span(obs.WAIT_PREPARE), \
+                ThreadPoolExecutor(max_workers=self.workers) as pool:
             preps = list(pool.map(prep, range(len(cases))))
         groups = defaultdict(list)
         for i, (prepped, prep_s) in enumerate(preps):

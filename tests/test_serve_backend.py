@@ -39,10 +39,10 @@ class TestBackendParity:
         dispatch counter, scan serves on the fused counter."""
         vec.reset_dispatch_counts()
         simulate("karate", "wcc", "hitgraph", serve_backend="pallas")
-        assert vec.DISPATCHES["pallas"] > 0
-        pallas_only = vec.DISPATCHES["fused"]
+        assert vec.dispatch_counts()["pallas"] > 0
+        pallas_only = vec.dispatch_counts()["fused"]
         simulate("karate", "wcc", "hitgraph", serve_backend="scan")
-        assert vec.DISPATCHES["fused"] > pallas_only
+        assert vec.dispatch_counts()["fused"] > pallas_only
 
     def test_default_matches_explicit_auto(self):
         a = simulate("karate", "pr", "accugraph")

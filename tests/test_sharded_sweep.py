@@ -24,6 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _SCRIPT = r"""
 import json
+from repro import obs
 from repro.serve import chaos
 from repro.serve.engine import BreakerConfig, RetryPolicy, SimService
 from repro.sim.memory import timing_variants
@@ -47,10 +48,13 @@ def digest(rows):
              r.report.row_hit_rate) for r in rows]
 
 
-out = {"clean": {}, "chaos": {}, "sharded_dispatches": {}}
+out = {"clean": {}, "chaos": {}, "sharded_dispatches": {},
+       "lane_slots": {}}
 for name, dev, wrk in (("d1", 1, 1), ("d2w2", 2, 2), ("d4", 4, 1)):
     sw = Sweeper(batch_memories=True, workers=wrk, devices=dev)
+    slots = obs.counts()["serve_lane_slots"]
     out["clean"][name] = digest(sweep(**KW, sweeper=sw))
+    out["lane_slots"][name] = obs.counts()["serve_lane_slots"] - slots
     out["sharded_dispatches"][name] = sw.stats.sharded_dispatches
 
 CASES = [SweepCase("karate", p, accelerator="hitgraph", memory=m)
@@ -94,6 +98,15 @@ class TestDeviceCountInvariance:
         assert forced4["sharded_dispatches"]["d1"] == 0
         assert forced4["sharded_dispatches"]["d4"] > 0
         assert forced4["sharded_dispatches"]["d2w2"] > 0
+
+    def test_lane_slots_equal_across_devices(self, forced4):
+        """Batches of 4 cases divide over 1, 2 and 4 devices without
+        padding: the sharded dispatches run exactly the lanes of the
+        single-device ones."""
+        slots = forced4["lane_slots"]
+        assert slots["d1"] > 0
+        assert slots["d4"] == slots["d1"]
+        assert slots["d2w2"] == slots["d1"]
 
     def test_chaos_rows_bit_identical_across_devices(self, forced4):
         """PR 7 fault plans + retries: surviving rows equal for any
