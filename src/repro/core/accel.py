@@ -251,7 +251,8 @@ class DevicePackedProgram:
     :class:`PackedProgram` (``pack_program`` is the NumPy reference; the
     parity is tested field by field), with the per-request row kinds
     pre-reduced to per-phase hit/conflict counts so finalization only
-    transfers ``O(P)`` integers."""
+    transfers ``O(P)`` integers (:func:`device_row_kinds` gives the
+    kinds themselves)."""
 
     issue: object            # int32[S, C, K] device
     meta: object             # int32[S, C, K] device
@@ -262,7 +263,6 @@ class DevicePackedProgram:
     names: List[str]
     requests: np.ndarray     # int64[P]
     offsets: np.ndarray      # int64[P+1]
-    kind: object             # int8[Npad] device (program order; tests)
     L_p: object              # int32[P_pad] device steps-per-phase
     hits_p: object           # int32[P_pad] device per-phase row hits
     confl_p: object          # int32[P_pad] device per-phase conflicts
@@ -313,25 +313,21 @@ def pack_program_device(program: SegmentedTrace, cfg: DRAMConfig,
         raise ValueError("issue cycles out of int32 range; chunk the trace")
     C = cfg.channels
     B = cfg.banks_per_channel
-    spec = cfg.decode_spec()
     N_pad = _bucket(N)
     P_pad = _bucket(P)
-    line32 = np.zeros(N_pad, dtype=np.int32)
-    line32[:N] = program.line_addr
     issue32 = np.zeros(N_pad, dtype=np.int32)
     issue32[:N] = program.issue
     offsets32 = np.full(P_pad + 1, N, dtype=np.int32)
     offsets32[:P + 1] = program.offsets
-    if open_row is None:
-        open_row = jnp.full((C, B), -1, dtype=jnp.int32)
-    else:
-        open_row = jnp.asarray(open_row, dtype=jnp.int32)
     vec.count_dispatch("device_pack")
+    obs.count("pack_requests", N)
+    obs.count("pack_slots", N_pad)
     (r_idx, c_idx, lane, issue_s, meta_s, valid_s, L_p, hits_p,
-     confl_p, kind, open_out, S, K) = vec._device_pack_core(
-        jnp.asarray(line32), jnp.asarray(issue32),
-        jnp.asarray(offsets32), jnp.int32(N), open_row,
-        spec=spec, C=C, B=B, banks=cfg.org.banks)
+     confl_p, open_out, S, K) = vec._device_pack_core(
+        _device_lines(program, N_pad), jnp.asarray(issue32),
+        jnp.asarray(offsets32), jnp.int32(N),
+        _device_open_row(cfg, open_row), spec=cfg.decode_spec(), C=C,
+        B=B, banks=cfg.org.banks)
     with obs.span(obs.DEVICE_WAIT):
         S = int(S)
         K = int(K)
@@ -345,9 +341,39 @@ def pack_program_device(program: SegmentedTrace, cfg: DRAMConfig,
         timing=vec.timing_params(cfg.timing),
         n_banks=B, banks_per_rank=cfg.org.banks,
         names=list(program.names), requests=requests,
-        offsets=np.asarray(program.offsets), kind=kind,
+        offsets=np.asarray(program.offsets),
         L_p=L_p, hits_p=hits_p, confl_p=confl_p, n_steps=S,
         open_row_final=open_out)
+
+
+def _device_lines(program: SegmentedTrace, N_pad: int):
+    """The program's line addresses as a zero-padded int32[N_pad] device
+    array."""
+    line32 = np.zeros(N_pad, dtype=np.int32)
+    line32[:len(program)] = program.line_addr
+    return jnp.asarray(line32)
+
+
+def _device_open_row(cfg: DRAMConfig, open_row):
+    """The int32[C, B] device row state entering a program (all banks
+    closed when ``open_row`` is None)."""
+    if open_row is None:
+        return jnp.full((cfg.channels, cfg.banks_per_channel), -1,
+                        dtype=jnp.int32)
+    return jnp.asarray(open_row, dtype=jnp.int32)
+
+
+def device_row_kinds(program: SegmentedTrace, cfg: DRAMConfig,
+                     open_row=None):
+    """Program-order row kinds (int8[N]: 0 hit / 1 empty / 2 conflict)
+    as the device pack classifies them; ``classify_rows`` is the host
+    reference.  The pack itself reduces the kinds to per-phase counts
+    and never builds this array."""
+    N = len(program)
+    return vec._device_row_kinds(
+        _device_lines(program, _bucket(N)), jnp.int32(N),
+        _device_open_row(cfg, open_row), spec=cfg.decode_spec(),
+        banks=cfg.org.banks)[:N]
 
 
 def _auto_pack_prefers_device() -> bool:

@@ -397,6 +397,18 @@ def pack_meta(bank: np.ndarray, miss: np.ndarray, confl: np.ndarray,
 # fixed-shape jitted dispatches, bit-identical to the NumPy packer in
 # ``repro.core.accel.pack_program`` (the reference implementation).
 #
+# The classify/block program moves request-length arrays only through
+# its two sorts, which carry their payloads as extra operands: the bank
+# sort carries the row, program index, (phase, channel) key and issue
+# cycle; the grouped sort takes its keys from the bank sort's output.
+# Everything else is elementwise, a prefix sum or prefix max, or segment
+# arithmetic over contiguous ranges: a phase is a prefix sum of markers
+# at the phase offsets, runs and blocks are numbered by prefix sums,
+# and per-bank, per-group and per-phase values are read or written at
+# segment bounds found by a searchsorted of the few segment keys into
+# the sorted keys.  No scatter, gather or binary search runs at the
+# request length.
+#
 # Shapes are bucketed: requests pad to the next power of two, phases to
 # the next power of two, steps to the fused-scan chunk ladder — so the
 # jit cache stays logarithmic in program size.  All transfers are int32
@@ -415,6 +427,54 @@ def _decode_device(line, spec, banks):
     return comps
 
 
+def _shift_in(x, fill):
+    """``x`` moved one place towards the end, ``fill`` entering first."""
+    return jnp.concatenate([jnp.full((1,), fill, x.dtype), x[:-1]])
+
+
+def _segments(sorted_keys, n_keys):
+    """``(start, end)``, int32[n_keys]: the range each key value
+    ``0..n_keys-1`` holds in the non-negative ``sorted_keys`` (``start ==
+    end`` where it is absent)."""
+    end = jnp.searchsorted(
+        sorted_keys, jnp.arange(n_keys, dtype=sorted_keys.dtype),
+        side="right").astype(jnp.int32)
+    return _shift_in(end, 0), end
+
+
+def _classify_bank_order(comps, n, open_row, payloads):
+    """Sort a padded program by global bank (program order within each
+    bank; padding last) and classify each request's row against the row
+    its bank had open before it (mirrors ``classify_rows``).
+
+    ``comps`` is the program's decode (:func:`_decode_device`),
+    ``payloads`` int32[Npad] program-order arrays the sort carries.
+    Returns ``(kind_o, bank_o, open_out, idx_o, payloads_o)`` in bank
+    order: int32 kinds (0 hit / 1 empty / 2 conflict; 0 on padding), the
+    global bank (``C * B`` on padding), the int32[C, B] row state after
+    the program, and the program index of each position."""
+    C, B = open_row.shape
+    Npad = comps["row"].shape[0]
+    idx = jnp.arange(Npad, dtype=jnp.int32)
+    bank = jnp.where(idx < n, comps["channel"] * B
+                     + comps["bank_in_channel"], C * B)
+    bank_o, idx_o, rows_o, *payloads_o = jax.lax.sort(
+        (bank, idx, comps["row"], *payloads), num_keys=2)
+    start, end = _segments(bank_o, C * B)
+    used = end > start
+    open_flat = open_row.reshape(-1)
+    # each bank's entering row, set where its segment starts
+    entering = jnp.zeros(Npad, jnp.int32).at[
+        jnp.where(used, start, Npad)].set(open_flat, mode="drop")
+    first = bank_o != _shift_in(bank_o, -1)
+    prev = jnp.where(first, entering, _shift_in(rows_o, 0))
+    kind_o = jnp.where(prev == rows_o, 0, jnp.where(prev == -1, 1, 2))
+    kind_o = jnp.where(bank_o < C * B, kind_o, 0)
+    open_out = jnp.where(used, rows_o[jnp.maximum(end - 1, 0)],
+                         open_flat).reshape(C, B)
+    return kind_o, bank_o, open_out, idx_o, payloads_o
+
+
 @functools.partial(jax.jit,
                    static_argnames=("spec", "C", "B", "banks"))
 def _device_pack_core(line, issue, offsets, n, open_row, spec, C, B,
@@ -429,64 +489,36 @@ def _device_pack_core(line, issue, offsets, n, open_row, spec, C, B,
     """
     Npad = line.shape[0]
     P_pad = offsets.shape[0] - 1
+    G = P_pad * C                         # (phase, channel) groups
     idx = jnp.arange(Npad, dtype=jnp.int32)
-    valid = idx < n
+    # program-order phase: a marker at each phase offset, summed forward
+    phase = jnp.cumsum(jnp.zeros(Npad, jnp.int32).at[offsets[1:]].add(
+        1, mode="drop"))
     comps = _decode_device(line, spec, banks)
-    ch = comps["channel"]
-    bank_in_ch = comps["bank_in_channel"]
-    row = comps["row"]
-    bank_global = ch * B + bank_in_ch
-    # ---- row-kind classification (mirrors classify_rows) --------------
-    sort_key = jnp.where(valid, bank_global, C * B)
-    order1 = jnp.argsort(sort_key, stable=True)
-    gbo = sort_key[order1]
-    rows_o = row[order1]
-    valid_o = valid[order1]
-    first = jnp.concatenate(
-        [jnp.ones(1, bool), gbo[1:] != gbo[:-1]])
-    last = jnp.concatenate([gbo[:-1] != gbo[1:], jnp.ones(1, bool)])
-    open_flat = jnp.concatenate(
-        [open_row.reshape(-1), jnp.full((1,), -1, jnp.int32)])
-    prev = jnp.where(
-        first, open_flat[gbo],
-        jnp.concatenate([rows_o[:1], rows_o[:-1]]))
-    kind_o = jnp.where(prev == rows_o, 0,
-                       jnp.where(prev == -1, 1, 2)).astype(jnp.int8)
-    kind_o = jnp.where(valid_o, kind_o, jnp.int8(0))
-    kind = jnp.zeros(Npad, jnp.int8).at[order1].set(kind_o)
-    open_out = open_row.reshape(-1).at[
-        jnp.where(last & valid_o, gbo, C * B)
-    ].set(rows_o, mode="drop").reshape(C, B)
+    key = jnp.where(idx < n, phase * C + comps["channel"], G)
+    # ---- row-kind classification in bank order ------------------------
+    kind_o, bank_o, open_out, idx_o, (key_o, issue_o) = \
+        _classify_bank_order(comps, n, open_row, (key, issue))
     # ---- K selection (traced form of choose_block_lanes) --------------
-    n_miss = jnp.sum(jnp.where(valid, kind != 0, False))
+    n_miss = jnp.sum(kind_o != 0)
     K = jnp.where(2 * n_miss < n, BLOCK_LANES, 1).astype(jnp.int32)
-    # ---- per-phase request ids + hit/conflict reductions --------------
-    phase = (jnp.searchsorted(offsets, idx, side="right") - 1
-             ).astype(jnp.int32)
-    hits_p = jnp.zeros(P_pad, jnp.int32).at[phase].add(
-        (kind == 0) & valid, mode="drop")
-    confl_p = jnp.zeros(P_pad, jnp.int32).at[phase].add(
-        (kind == 2) & valid, mode="drop")
+    # ---- grouped order: (phase, channel), then program order ----------
+    meta_o = ((bank_o % B)
+              | ((kind_o != 0).astype(jnp.int32) << 8)
+              | ((kind_o == 2).astype(jnp.int32) << 9)
+              | ((bank_o < C * B).astype(jnp.int32) << 10))
+    key_s, _, meta_s, issue_s = jax.lax.sort(
+        (key_o, idx_o, meta_o, issue_o), num_keys=2)
+    valid_s = key_s < G
+    miss_s = (meta_s & META_MISS) != 0
+    bank_s = meta_s & 0xFF
     # ---- block decomposition within (phase, channel) streams ----------
-    key = jnp.where(valid, phase * C + ch, P_pad * C)
-    order2 = jnp.argsort(key, stable=True)
-    key_s = key[order2]
-    kind_s = kind[order2]
-    miss_s = kind_s != 0
-    valid_s = valid[order2]
-    bank_s = bank_in_ch[order2]
-    group_first = jnp.concatenate(
-        [jnp.ones(1, bool), key_s[1:] != key_s[:-1]])
-    prev_miss = jnp.concatenate([jnp.zeros(1, bool), miss_s[:-1]])
-    run_start = group_first | miss_s | prev_miss
-    run_id = jnp.cumsum(run_start.astype(jnp.int32)) - 1
-    run_len = jnp.zeros(Npad, jnp.int32).at[run_id].add(1)
-    run_off = jnp.cumsum(run_len) - run_len
-    pos = idx - run_off[run_id]
+    group_first = key_s != _shift_in(key_s, -1)
+    run_start = group_first | miss_s | _shift_in(miss_s, False)
+    pos = idx - jax.lax.cummax(jnp.where(run_start, idx, 0))
     lane = pos % K
-    bpr = (run_len + K - 1) // K
-    block_off = jnp.cumsum(bpr) - bpr
-    block_id = block_off[run_id] + pos // K
+    # a run's blocks start at pos 0, K, 2K, ...: number them in order
+    block_id = jnp.cumsum((lane == 0).astype(jnp.int32)) - 1
     # first block of the current group, propagated forward (block_id is
     # globally non-decreasing in grouped order)
     fb = jax.lax.cummax(jnp.where(group_first, block_id, -1))
@@ -500,23 +532,42 @@ def _device_pack_core(line, issue, offsets, n, open_row, spec, C, B,
         rb = rb + jnp.concatenate(
             [jnp.zeros(j, jnp.int32),
              (kb[j:] == kb[:-j]).astype(jnp.int32)])
-    group_last = jnp.concatenate([group_first[1:], jnp.ones(1, bool)])
-    n_blocks = jnp.zeros(P_pad * C, jnp.int32).at[
-        jnp.where(group_last & valid_s, key_s, P_pad * C)
-    ].set(block_rank + 1, mode="drop")
+    # ---- steps per phase: each group's block count at its last element
+    g_start, g_end = _segments(key_s, G)
+    n_blocks = jnp.where(g_end > g_start,
+                         block_rank[jnp.maximum(g_end - 1, 0)] + 1, 0)
     L_p = n_blocks.reshape(P_pad, C).max(axis=1)
     step_starts = jnp.cumsum(L_p) - L_p
     S = L_p.sum()
-    phase_s = jnp.minimum(key_s // C, P_pad - 1)
-    r_idx = step_starts[phase_s] + block_rank
-    issue_s = issue[order2]
-    meta_s = (bank_s
-              | (miss_s.astype(jnp.int32) << 8)
-              | ((kind_s == 2).astype(jnp.int32) << 9)
-              | (valid_s.astype(jnp.int32) << 10)
-              | (rb << META_RB_SHIFT))
-    return (r_idx, ch[order2], lane, issue_s, meta_s, valid_s,
-            L_p, hits_p, confl_p, kind, open_out, S, K)
+    # each phase's first step, set where the phase starts and carried
+    # forward (phases are contiguous and step_starts never decreases)
+    p_start = g_start.reshape(P_pad, C)[:, 0]
+    r_idx = jax.lax.cummax(jnp.zeros(Npad, jnp.int32).at[p_start].max(
+        step_starts, mode="drop")) + block_rank
+    # ---- per-phase hits/conflicts: prefix sums read at phase bounds ---
+    bounds = jnp.concatenate([p_start, g_end[-1:]])
+
+    def per_phase(x):
+        cs = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                              jnp.cumsum(x.astype(jnp.int32))])[bounds]
+        return cs[1:] - cs[:-1]
+
+    hits_p = per_phase(valid_s & ~miss_s)
+    confl_p = per_phase((meta_s & META_CONFL) != 0)
+    meta_s = meta_s | (rb << META_RB_SHIFT)
+    return (r_idx, key_s % C, lane, issue_s, meta_s, valid_s,
+            L_p, hits_p, confl_p, open_out, S, K)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "banks"))
+def _device_row_kinds(line, n, open_row, spec, banks):
+    """Program-order int8 row kinds of a padded program (0 on padding):
+    the device pack's classification with its bank-order permutation
+    undone.  Not on the pack path, which never needs program order."""
+    kind_o, _, _, idx_o, _ = _classify_bank_order(
+        _decode_device(line, spec, banks), n, open_row, ())
+    return jnp.zeros(line.shape[0], jnp.int8).at[idx_o].set(
+        kind_o.astype(jnp.int8))
 
 
 @functools.partial(jax.jit, static_argnames=("S_pad", "C", "K"))

@@ -20,7 +20,9 @@ serves batch groups from worker threads): the serve and pack dispatch
 counts (``core.vectorized.dispatch_counts``) and ``serve_lane_slots``,
 the ``steps x channels x lanes`` (times the batch) that the serve
 dispatches ran, whose ratio with the requests served is the serve
-layer's useful-lane share.
+layer's useful-lane share; and ``pack_requests`` and ``pack_slots``, the
+requests the device pack packed and the power-of-two request slots its
+programs ran over, whose ratio is the pack's useful share.
 """
 
 from __future__ import annotations
@@ -60,7 +62,8 @@ def span(name: str) -> jax.profiler.TraceAnnotation:
 
 
 _COUNTS = {"packed": 0, "fused": 0, "fused_batch": 0, "device_pack": 0,
-           "pallas": 0, "serve_lane_slots": 0}
+           "pallas": 0, "serve_lane_slots": 0, "pack_requests": 0,
+           "pack_slots": 0}
 _LOCK = threading.Lock()
 
 

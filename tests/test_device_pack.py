@@ -11,14 +11,18 @@ the sharded sweep executor.
 """
 
 import dataclasses
+import functools
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import vectorized as vec
 from repro.core.accel import (VectorizedDRAM, device_pack_supported,
-                              finalize_program, finalize_program_device,
-                              pack_program, pack_program_device)
+                              device_row_kinds, finalize_program,
+                              finalize_program_device, pack_program,
+                              pack_program_device)
 from repro.core.dram import PRESETS
 from repro.core.trace import SegmentedTrace
 from repro.graphs.generators import rmat
@@ -47,29 +51,102 @@ def _phase_tuples(report_or_backend):
              p.row_conflicts) for p in report_or_backend.phases]
 
 
+def _segmented(lines_per_phase, rng):
+    """A program of the given per-phase line addresses, empty phases
+    kept (``from_phases`` drops them), issue cycles sorted at random."""
+    lens = [len(x) for x in lines_per_phase]
+    offsets = np.zeros(len(lens) + 1, dtype=np.int64)
+    np.cumsum(lens, out=offsets[1:])
+    issue = np.concatenate([np.sort(rng.integers(0, 4 * n + 1, n))
+                            for n in lens])
+    line = np.concatenate(lines_per_phase).astype(np.int64)
+    return SegmentedTrace(line, np.zeros(len(line), dtype=bool),
+                          issue.astype(np.int64), offsets,
+                          [f"p{p}" for p in range(len(lens))])
+
+
+def _parity_programs(case, rng):
+    """``(program, earlier program or None)`` of a parity case; the
+    earlier program's final row state enters the program."""
+    if case in (False, True):
+        return _random_program(rng, sequential=case), None
+    if case == "miss_heavy":            # a new row almost every request
+        return _random_program(rng, span=1 << 26, max_n=600), None
+    if case == "long_runs":             # runs of 12-40 hits on one row
+        return _segmented(
+            [np.repeat(rng.integers(0, 1 << 18, 12),
+                       rng.integers(12, 40, 12)) for _ in range(4)],
+            rng), None
+    if case == "empty_phase":           # first, middle and last phases
+        return _segmented(
+            [rng.integers(0, 1 << 12, n) for n in (0, 90, 0, 0, 140, 0)],
+            rng), None
+    if case in ("n_pow2", "n_pow2_plus_1"):
+        n = 1024 + (case == "n_pow2_plus_1")
+        cut = np.sort(rng.choice(np.arange(1, n), 2, replace=False))
+        lines = np.arange(n) // 3 + rng.integers(0, 1 << 14)
+        return _segmented(np.split(lines, cut), rng), None
+    if case == "padded_phases":         # P = 9 of P_pad = 16
+        return _random_program(rng, n_phases=9), None
+    if case == "open_row_in":
+        return (_random_program(rng, sequential=True, span=1 << 12),
+                _random_program(rng, span=1 << 12))
+    raise ValueError(case)
+
+
+#: the parity cases: the random (``False``) and hit-dominated (``True``)
+#: programs, then the shapes the device pack's segment arithmetic has to
+#: get right
+PARITY_CASES = [False, True, "miss_heavy", "long_runs", "empty_phase",
+                "n_pow2", "n_pow2_plus_1", "padded_phases", "open_row_in"]
+
+
 class TestDevicePackParity:
     """The device pack must reproduce every array of the NumPy reference
     bit-for-bit: blocked streams, boundaries, kinds, and the finish
     times / statistics the fused scan derives from them."""
 
     @pytest.mark.parametrize("preset", list(PRESETS))
-    @pytest.mark.parametrize("sequential", [False, True])
-    def test_packed_arrays_match(self, preset, sequential):
+    @pytest.mark.parametrize("case", PARITY_CASES)
+    def test_packed_arrays_match(self, preset, case):
         cfg = PRESETS[preset]()
-        rng = np.random.default_rng(hash((preset, sequential)) % 2**31)
-        prog = _random_program(rng, sequential=sequential)
+        rng = np.random.default_rng(hash((preset, case)) % 2**31)
+        prog, earlier = _parity_programs(case, rng)
+        open_h = open_d = None
+        if earlier is not None:
+            open_h = pack_program(earlier, cfg).open_row_final
+            open_d = pack_program_device(earlier, cfg).open_row_final
+            assert np.array_equal(np.asarray(open_d), open_h)
         assert device_pack_supported(prog, cfg)
-        host = pack_program(prog, cfg)
-        dev = pack_program_device(prog, cfg)
+        host = pack_program(prog, cfg, open_row=open_h)
+        dev = pack_program_device(prog, cfg, open_row=open_d)
         assert np.array_equal(np.asarray(dev.issue), host.issue)
         assert np.array_equal(np.asarray(dev.meta), host.meta)
         assert np.array_equal(np.asarray(dev.boundary), host.boundary)
-        assert np.array_equal(np.asarray(dev.kind)[:len(prog)], host.kind)
+        assert np.array_equal(
+            np.asarray(device_row_kinds(prog, cfg, open_row=open_d)),
+            host.kind)
         assert np.array_equal(np.asarray(dev.open_row_final),
                               host.open_row_final)
         assert dev.n_steps == host.n_steps
         assert dev.signature == (tuple(host.issue.shape), host.n_banks,
                                  host.banks_per_rank)
+        P = prog.n_phases
+        L_p = np.asarray(dev.L_p)
+        assert np.array_equal((np.cumsum(L_p) - L_p)[:P],
+                              host.step_starts)
+        assert not L_p[P:].any()
+        phase = np.repeat(np.arange(P), host.requests)
+        for got, want in ((dev.hits_p, host.kind == 0),
+                          (dev.confl_p, host.kind == 2)):
+            got = np.asarray(got)
+            assert np.array_equal(got[:P],
+                                  np.bincount(phase, want, minlength=P))
+            assert not got[P:].any()
+        if case == "miss_heavy":
+            assert host.issue.shape[2] == 1
+        if case == "long_runs":
+            assert host.issue.shape[2] == vec.BLOCK_LANES
 
     def test_finish_times_and_stats_match(self):
         cfg = PRESETS["comparability"]()
@@ -239,3 +316,47 @@ class TestPackCacheReuse:
         # same geometry + clock as the accugraph default -> shared model
         key_count = len(sess._models)
         assert key_count == 1
+
+
+class TestDevicePackStructure:
+    """The classify/block program moves request-length arrays only
+    through its two sorts: every scatter, gather and binary search in it
+    runs over a few segment keys, not over the requests."""
+
+    N_PAD, P_PAD = 1 << 12, 8
+
+    def _primitives(self, jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (tuple, list))
+                            else (param,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from self._primitives(sub)
+
+    @pytest.mark.parametrize("preset", ["hitgraph", "hbm2e"])
+    def test_core_has_no_request_length_scatter_or_gather(self, preset):
+        cfg = PRESETS[preset]()
+        C, B = cfg.channels, cfg.banks_per_channel
+        assert max(C * B, self.P_PAD * C) < self.N_PAD
+        req = jax.ShapeDtypeStruct((self.N_PAD,), jnp.int32)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            vec._device_pack_core, spec=cfg.decode_spec(), C=C, B=B,
+            banks=cfg.org.banks))(
+            req, req, jax.ShapeDtypeStruct((self.P_PAD + 1,), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((C, B), jnp.int32))
+        eqns = list(self._primitives(jaxpr.jaxpr))
+        sorts = [e for e in eqns if e.primitive.name == "sort"]
+        assert len(sorts) == 2
+        assert all(v.aval.shape == (self.N_PAD,)
+                   for e in sorts for v in e.invars)
+        scatters = [e for e in eqns
+                    if e.primitive.name.startswith("scatter")]
+        gathers = [e for e in eqns if e.primitive.name == "gather"]
+        assert scatters and gathers
+        for e in scatters:                 # (operand, indices, updates)
+            assert e.invars[2].aval.size < self.N_PAD, e
+        for e in gathers:                  # (operand, indices)
+            assert e.invars[1].aval.shape[0] < self.N_PAD, e

@@ -77,7 +77,7 @@ def test_dispatch_counts_keep_their_five_keys():
     assert set(vec.dispatch_counts()) == {
         "packed", "fused", "fused_batch", "device_pack", "pallas"}
     assert set(obs.counts()) == set(vec.dispatch_counts()) | {
-        "serve_lane_slots"}
+        "serve_lane_slots", "pack_requests", "pack_slots"}
 
 
 def test_counters_count_and_reset():
